@@ -62,6 +62,9 @@ def main(argv=None) -> None:
     ap.add_argument("--out-dir", default="benchmarks/out")
     args = ap.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
+
     if args.smoke:
         from benchmarks import build_throughput as B
         from benchmarks import decode_throughput as D
@@ -105,20 +108,20 @@ def main(argv=None) -> None:
     for ln in summary:
         print(ln)
 
+    if failed:
+        raise SystemExit(f"[bench] figures failed: {failed}")
     if args.smoke:
-        _enforce_smoke_gates(failed, ran)
+        _enforce_smoke_gates(ran)
 
 
-def _enforce_smoke_gates(failed, ran) -> None:
-    """--smoke is the CI entry point: a failed smoke figure or a build-
-    pipeline regression must fail the run, not just print.  Gates are
+def _enforce_smoke_gates(ran) -> None:
+    """--smoke is the CI entry point: a build-pipeline regression must
+    fail the run, not just print (a failed figure already has).  Gates are
     *ratios* measured within the same run (old-vs-new build speedup >= 1.0),
     not absolute times, so shared CI runners don't flake.  The build gate
     only fires when this run actually produced BENCH_build.json (--only may
     have selected a different figure — never gate on a stale file)."""
     import json
-    if failed:
-        raise SystemExit(f"[bench] smoke figures failed: {failed}")
     if "serving_load" in ran:
         with open("BENCH_serving.json") as f:
             srv = json.load(f)

@@ -17,10 +17,12 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.decode import KVCacheIndex, KVSpec, LSHDecoder
+from repro.launch import compile_cache
 from repro.models import layers as L
 
 
 def main():
+    compile_cache.enable()
     rng = np.random.default_rng(0)
     b, S, hk, g, dh = 1, 4096, 4, 4, 64
     h = hk * g

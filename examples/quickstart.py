@@ -16,9 +16,11 @@ sys.path.insert(0, "src")
 
 import repro
 from repro.api import IndexSpec, SearchRequest
+from repro.launch import compile_cache
 
 
 def main():
+    compile_cache.enable()
     rng = np.random.default_rng(0)
     n, d, nq, k = 30000, 64, 32, 10
 

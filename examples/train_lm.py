@@ -10,6 +10,7 @@ import sys
 
 sys.path.insert(0, "src")
 
+from repro.launch import compile_cache
 from repro.launch.train import main as train_main
 
 
@@ -20,6 +21,7 @@ def main():
     ap.add_argument("--no-reduced", action="store_true")
     ap.add_argument("--ckpt-dir", default="/tmp/repro_train_ckpt")
     args = ap.parse_args()
+    compile_cache.enable()
 
     argv = ["--arch", args.arch, "--steps", str(args.steps),
             "--batch", "8", "--seq", "64", "--ckpt-dir", args.ckpt_dir,

@@ -9,21 +9,18 @@ The finale snapshots the live index and restarts the service from the
 snapshot — no rebuild; a durability phase serves a WAL-backed
 ``DurableIndex``, kills it with an un-checkpointed tail, and recovers it
 bit-identically (docs/DESIGN.md §13); and a last phase serves the
-*sharded* PDET index on a forced 4-device host mesh, bit-identical to
-its single-device twin (docs/DESIGN.md §7).
+*sharded* PDET index over the devices present (up to four), bit-identical
+to its single-device twin (docs/DESIGN.md §7).
 
   PYTHONPATH=src python examples/vector_search_service.py
+
+It runs on whatever backend JAX finds.  On a CPU-only host, give the PDET
+phase a four-device mesh with
+``JAX_PLATFORMS=cpu XLA_FLAGS=--xla_force_host_platform_device_count=4``.
 """
 
-import os
-
-# The PDET phase wants a multi-device mesh; on a CPU host we force four
-# host-platform devices (must happen before jax initializes).
-os.environ.setdefault("XLA_FLAGS",
-                      "--xla_force_host_platform_device_count=4")
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
-
 import dataclasses
+import os
 import sys
 import tempfile
 import time
@@ -36,11 +33,13 @@ sys.path.insert(0, "src")
 
 import repro
 from repro.api import IndexSpec, PlacementSpec, SearchRequest
+from repro.launch import compile_cache
 from repro.serving import (Answer, COMPACTION_SWAP, ENGINE_CALL, FaultPlan,
                            InjectedFault, Rejected, ServingRuntime)
 
 
 def main():
+    compile_cache.enable()
     rng = np.random.default_rng(1)
     n, d, n_requests = 20000, 48, 96
 

@@ -47,10 +47,11 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.api import registry as engine_registry
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
 from repro.core import encoding as enc
 from repro.core import hashing
+from repro.core.hashing import project_query
 from repro.core.detree import DEForest, build_tree, fused_forest_arrays
 from repro.core.query import (FusedPlan, QueryConfig, QueryResult,
                               _merge_candidates, fused_round_update,
@@ -141,7 +142,7 @@ def _knn_local(data_local: jax.Array, forest: DEForest, A: jax.Array,
     cap = min(int(params.beta * n_global) + cfg.k + round_cap,
               n_local + round_cap)
     thresh = jnp.asarray(params.beta * n_global + cfg.k, jnp.float32)
-    q_proj = (q @ A).reshape(L, K)
+    q_proj = project_query(q, A).reshape(L, K)
 
     def cond(state):
         rnd, r, ids, d, done = state
@@ -365,7 +366,7 @@ def serial_reference_query(data: jax.Array, A: jax.Array, parts: dict,
     K, L = params.K, params.L
     out_ids, out_d = [], []
     for q in queries:
-        q_proj = (q @ A).reshape(L, K)
+        q_proj = project_query(q, A).reshape(L, K)
         M = min(cfg.M, forests[0].n_leaves)
         round_cap = L * M * leaf_size
         cap = min(int(params.beta * n) + cfg.k + round_cap,
@@ -522,7 +523,8 @@ def pdet_query_batch(forest: DEForest, A: jax.Array, params: LSHParams,
     n_local = n_pad // n_shards
     thresh = jnp.asarray(params.beta * n + cfg.k, jnp.float32)
     interpret = cfg.dist_impl == "pallas_interpret"
-    q_proj = (queries @ A).reshape(B, L, K).transpose(1, 0, 2)   # (L, B, K)
+    q_proj = project_query(queries, A).reshape(B, L, K).transpose(
+        1, 0, 2)                                                 # (L, B, K)
     done0 = (jnp.zeros((B,), jnp.bool_) if n_active is None
              else jnp.arange(B) >= jnp.asarray(n_active))
 

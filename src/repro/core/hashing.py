@@ -30,9 +30,14 @@ def project(data: jax.Array, A: jax.Array, *, impl: str = "auto") -> jax.Array:
         return kops.lsh_project(data, A,
                                 interpret=(impl == "pallas_interpret"))
     # XLA path (used by dry-run lowering and CPU execution).
-    return jnp.dot(data, A, preferred_element_type=jnp.float32)
+    return project_query(data, A)
 
 
 def project_query(q: jax.Array, A: jax.Array) -> jax.Array:
-    """Project one query or a batch of queries: (..., d) -> (..., L*K)."""
-    return jnp.dot(q, A, preferred_element_type=jnp.float32)
+    """Project one query or a batch of queries: (..., d) -> (..., L*K).
+
+    f32 products on every backend (the TPU default would round the
+    operands to bf16): data and queries must hash through the same
+    function for the leaf bounds to stay admissible."""
+    return jnp.dot(q, A, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)

@@ -56,6 +56,7 @@ import jax.numpy as jnp
 from repro.api import registry as engine_registry
 from repro.core import candidates as cand
 from repro.core.detree import DEForest, leaf_bounds
+from repro.core.hashing import project_query
 from repro.core.theory import LSHParams
 
 
@@ -256,7 +257,7 @@ def knn_query(data: jax.Array, forest: DEForest, A: jax.Array,
     n = data.shape[0]
     K, L = params.K, params.L
     cap = _auto_cap(n, params, cfg, forest)
-    q_proj = (q @ A).reshape(L, K)                              # Alg. 5 line 4
+    q_proj = project_query(q, A).reshape(L, K)                  # Alg. 5 line 4
     thresh = jnp.asarray(params.beta * n + cfg.k, jnp.float32)
 
     def cond(state):
@@ -394,7 +395,8 @@ def fused_query_batch(data: jax.Array, forest: DEForest, A: jax.Array,
     K, L = params.K, params.L
     if plan is None:
         plan = make_fused_plan(data, forest)
-    q_proj = (queries @ A).reshape(B, L, K).transpose(1, 0, 2)   # (L, B, K)
+    q_proj = project_query(queries, A).reshape(B, L, K).transpose(
+        1, 0, 2)                                                 # (L, B, K)
     thresh = jnp.asarray(params.beta * n + cfg.k, jnp.float32)
     interpret = cfg.dist_impl == "pallas_interpret"
     nl, ls = forest.n_leaves, forest.leaf_size
@@ -435,9 +437,11 @@ def fused_query_batch(data: jax.Array, forest: DEForest, A: jax.Array,
                                       0).sum((0, 2)).astype(jnp.int32)
         # Fold the round into the id-indexed table: inv_perm turns each
         # tree's sorted-order row into id order (gather, not scatter).
-        by_id = jnp.min(
-            jnp.take_along_axis(dmat, plan.inv_perm[:, None, :], axis=2),
-            axis=0)                                              # (B, n)
+        # One (B, n) gather per tree: a batched take_along_axis would
+        # materialize (L, B, n) index and transpose buffers (GBs at n=1M).
+        by_id = functools.reduce(jnp.minimum, [
+            jnp.take(dmat[l], plan.inv_perm[l], axis=1)
+            for l in range(L)])                                  # (B, n)
         best, r, done, rounds = fused_round_update(
             best, by_id, r, done, rounds, rnd, params=params, k=cfg.k,
             thresh=thresh)
@@ -548,7 +552,7 @@ def rc_ann_query(data: jax.Array, forest: DEForest, A: jax.Array,
     found, or an invalid id (= n) when the algorithm would return nothing."""
     n = data.shape[0]
     cap = _auto_cap(n, params, cfg, forest)
-    q_proj = (q @ A).reshape(params.L, params.K)
+    q_proj = project_query(q, A).reshape(params.L, params.K)
     ids, ok = range_query_round(forest, q_proj,
                                 jnp.asarray(params.epsilon * r), cfg.M,
                                 mode=cfg.mode, bounds_impl=cfg.bounds_impl,

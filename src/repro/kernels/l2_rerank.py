@@ -22,6 +22,7 @@ def _kernel(q_ref, c_ref, o_ref):
     qq = jnp.sum(q * q, axis=1, keepdims=True)          # (bq, 1)
     cc = jnp.sum(c * c, axis=1)[None, :]                # (1, bc)
     qc = jax.lax.dot_general(q, c, (((1,), (1,)), ((), ())),
+                             precision=jax.lax.Precision.HIGHEST,
                              preferred_element_type=jnp.float32)
     o_ref[...] = jnp.sqrt(jnp.maximum(qq - 2.0 * qc + cc, 0.0))
 

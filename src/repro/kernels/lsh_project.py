@@ -21,6 +21,7 @@ def _kernel(x_ref, a_ref, o_ref):
     a = a_ref[...]
     o_ref[...] = jax.lax.dot_general(
         x, a, (((1,), (0,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
 
 

@@ -76,10 +76,14 @@ def encode_pack(proj, breakpoints, *, K: int, L: int,
     if not _use_pallas(interpret):
         return _ref.encode_pack(proj, breakpoints, K=K, L=L)
     n = proj.shape[0]
-    pp = _pad_to(proj, 0, block_n)
-    outs = _bf.encode_pack(pp, breakpoints, K=K, L=L, block_n=block_n,
-                           interpret=interpret)
-    return tuple(o[:, :n] for o in outs)
+    block_n = _build_block(block_n, interpret)
+    pp = _pad_to(_pad_to(_dim_major(proj, L, K), 0, block_n), 1, 128)
+    bp_t = _pad_to(_dim_major(breakpoints.T, L, K), 1, 128)
+    codes, key_hi, key_lo = _bf.encode_pack(pp, bp_t, K=K, L=L,
+                                            block_n=block_n,
+                                            interpret=interpret)
+    proj_t = proj.reshape(n, L, K).transpose(1, 0, 2)
+    return proj_t, _per_tree(codes[:, :n], L, K), key_hi[:, :n], key_lo[:, :n]
 
 
 @functools.partial(jax.jit, static_argnames=("K", "L", "interpret",
@@ -93,11 +97,31 @@ def project_encode_pack(x, a, breakpoints, *, K: int, L: int,
     if not _use_pallas(interpret):
         return _ref.project_encode_pack(x, a, breakpoints, K=K, L=L)
     n = x.shape[0]
+    block_n = _build_block(block_n, interpret)
     xp = _pad_to(_pad_to(x, 0, block_n), 1, 128)
-    ap = _pad_to(a, 0, 128)
-    outs = _bf.project_encode_pack(xp, ap, breakpoints, K=K, L=L,
-                                   block_n=block_n, interpret=interpret)
-    return tuple(o[:, :n] for o in outs)
+    ap = _pad_to(_pad_to(_dim_major(a, L, K), 0, 128), 1, 128)
+    bp_t = _pad_to(_dim_major(breakpoints.T, L, K), 1, 128)
+    proj, codes, key_hi, key_lo = _bf.project_encode_pack(
+        xp, ap, bp_t, K=K, L=L, block_n=block_n, interpret=interpret)
+    return (_per_tree(proj[:, :n], L, K), _per_tree(codes[:, :n], L, K),
+            key_hi[:, :n], key_lo[:, :n])
+
+
+def _build_block(block_n: int, interpret: bool) -> int:
+    """The build kernels put rows on the 128-lane axis: round the chunk up
+    to a lane multiple for Mosaic (interpret mode takes any chunk)."""
+    return block_n if interpret else -(-block_n // 128) * 128
+
+
+def _dim_major(x: jax.Array, L: int, K: int) -> jax.Array:
+    """Reorder the trailing (tree-major) L*K axis to dim-major K*L."""
+    lead = x.shape[:-1]
+    return jnp.swapaxes(x.reshape(*lead, L, K), -1, -2).reshape(*lead, K * L)
+
+
+def _per_tree(rows: jax.Array, L: int, K: int) -> jax.Array:
+    """(K*L, n) dim-major kernel rows -> the per-tree (L, n, K) layout."""
+    return rows.reshape(K, L, -1).transpose(1, 2, 0)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "block_l"))
@@ -145,9 +169,9 @@ def range_rerank(q, q_proj, r_eff, leaf_lo, leaf_hi, leaf_valid, breakpoints,
     leaves per (tree, lane) are admitted alongside the radius box.
 
     Pads the query batch to ``block_q`` (padded lanes get r_eff = -1 so they
-    admit nothing), the leaf axis to ``block_l`` (padded leaves invalid) and
-    the feature dim to the 128-lane MXU width (zero padding preserves
-    distances).  ``live`` is the optional (L, nl*leaf_size) per-point
+    admit nothing), the leaf operands to ``block_l`` leaves (padded leaves
+    invalid) and the feature dim to the 128-lane MXU width (zero padding
+    preserves distances).  ``live`` is the optional (L, nl*leaf_size) per-point
     tombstone mask in sorted order (None = all live); dead points emit +inf
     inside the kernel tile, so deletes cost no extra pass.  Returns
     (L, B, nl*leaf_size).
@@ -165,23 +189,35 @@ def range_rerank(q, q_proj, r_eff, leaf_lo, leaf_hi, leaf_valid, breakpoints,
                                  leaf_valid, breakpoints, points, point_valid,
                                  live, leaf_size=leaf_size)
     L, B, K = q_proj.shape
-    nl = leaf_lo.shape[1]
-    npts = nl * leaf_size
+    tile = block_l * leaf_size
     qp_b = _pad_to(_pad_to(q, 0, block_q), 1, 128)
     qproj_b = _pad_to(q_proj, 1, block_q)
     r2 = jnp.broadcast_to(r_eff, (L, B)) if r_eff.ndim == 1 else r_eff
-    r_b = _pad_to(r2, 1, block_q, value=-1.0)
-    lo_b = _pad_to(leaf_lo.astype(jnp.int32), 1, block_l)
-    hi_b = _pad_to(leaf_hi.astype(jnp.int32), 1, block_l)
-    lv_b = _pad_to(leaf_valid.astype(jnp.int32), 1, block_l)
-    pts_b = _pad_to(_pad_to(points, 1, block_l * leaf_size), 2, 128)
-    pv_b = _pad_to(point_valid.astype(jnp.int32), 1, block_l * leaf_size)
-    lm_b = _pad_to(live.astype(jnp.int32), 1, block_l * leaf_size)
-    out = _rr.range_rerank(qp_b, qproj_b, r_b, lo_b, hi_b, lv_b, breakpoints,
-                           pts_b, pv_b, lm_b, leaf_size=leaf_size,
-                           block_q=block_q, block_l=block_l,
-                           interpret=interpret)
-    return out[:, :B, :npts]
+    r_b = _pad_to(r2, 1, block_q, value=-1.0)[..., None]           # (L, B, 1)
+    # Leaf bounding-box edge coordinates (the gather of ref.leaf_bounds,
+    # done once per call) + validity, laid out per leaf block as rows.
+    bp_t = jnp.swapaxes(breakpoints, 1, 2)                         # (L, E, K)
+    E = bp_t.shape[1]
+
+    def edge(idx):
+        idx = jnp.clip(idx.astype(jnp.int32), 0, E - 1)
+        return jnp.take_along_axis(bp_t, idx, axis=1)              # (L, nl, K)
+
+    edges = jnp.concatenate(
+        [edge(leaf_lo), edge(leaf_hi.astype(jnp.int32) + 1),
+         leaf_valid.astype(jnp.float32)[..., None]], axis=2)       # (L, nl, 2K+1)
+    edges = _pad_to(edges, 1, block_l)
+    edges = jnp.swapaxes(edges.reshape(L, -1, block_l, 2 * K + 1), 2, 3)
+    # The point rows are NOT padded to the leaf block: the kernel's last
+    # point/output block may be ragged (its tail is masked off by pm_b),
+    # which keeps a copy of the (L, n, d) points out of every round.
+    pts_b = _pad_to(points, 2, 128)
+    pm = (point_valid.astype(jnp.bool_) & live.astype(jnp.bool_))
+    pm_b = _pad_to(pm.astype(jnp.float32), 1, tile).reshape(L, -1, 1, tile)
+    out = _rr.range_rerank(qp_b, qproj_b, r_b, edges, pts_b, pm_b,
+                           leaf_size=leaf_size, block_q=block_q,
+                           block_l=block_l, interpret=interpret)
+    return out[:, :B]
 
 
 def range_rerank_heads(q, q_proj, r_eff, leaf_lo, leaf_hi, leaf_valid,

@@ -16,7 +16,8 @@ import jax.numpy as jnp
 
 def lsh_project(x: jax.Array, a: jax.Array) -> jax.Array:
     """(n, d) @ (d, m) -> (n, m) in f32 accumulation."""
-    return jnp.dot(x, a, preferred_element_type=jnp.float32)
+    return jnp.dot(x, a, precision=jax.lax.Precision.HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def encode_bins(coords: jax.Array, breakpoints: jax.Array) -> jax.Array:
@@ -157,7 +158,8 @@ def l2_rerank(q: jax.Array, c: jax.Array) -> jax.Array:
     """Exact Euclidean distances: q (b, d), c (m, d) -> (b, m)."""
     qq = (q.astype(jnp.float32) ** 2).sum(-1, keepdims=True)      # (b, 1)
     cc = (c.astype(jnp.float32) ** 2).sum(-1)[None, :]            # (1, m)
-    qc = jnp.dot(q, c.T, preferred_element_type=jnp.float32)
+    qc = jnp.dot(q, c.T, precision=jax.lax.Precision.HIGHEST,
+                 preferred_element_type=jnp.float32)
     return jnp.sqrt(jnp.maximum(qq - 2.0 * qc + cc, 0.0))
 
 

@@ -9,26 +9,19 @@ never touches jax device state.  The production target is TPU v5e:
 from __future__ import annotations
 
 import jax
-
-try:  # jax >= 0.5: explicit-sharding axis types
-    from jax.sharding import AxisType
-
-    def _axis_kw(n_axes: int) -> dict:
-        return {"axis_types": (AxisType.Auto,) * n_axes}
-except ImportError:  # older jax: every mesh axis is implicitly Auto
-    def _axis_kw(n_axes: int) -> dict:
-        return {}
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_kw(len(axes)))
+    return make_mesh(shape, axes)
 
 
 def make_mesh(shape, axes):
     """Arbitrary mesh (tests / reduced dry-runs)."""
-    return jax.make_mesh(tuple(shape), tuple(axes), **_axis_kw(len(axes)))
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def mesh_from_placement(placement, *, devices=None):
@@ -55,7 +48,7 @@ def mesh_from_placement(placement, *, devices=None):
     return Mesh(grid, placement.mesh_axes)
 
 
-# TPU v5e hardware constants (roofline targets; see EXPERIMENTS.md §Roofline)
+# TPU v5e hardware constants (roofline targets; Google Cloud "TPU v5e" docs)
 PEAK_FLOPS_BF16 = 197e12        # per chip
 HBM_BW = 819e9                  # bytes/s per chip
 ICI_BW = 50e9                   # bytes/s per link (intra-pod)

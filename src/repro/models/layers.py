@@ -308,7 +308,7 @@ def _decode_attention_cp(q, k_cache, v_cache, length, rules):
     structure explicitly."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.compat import shard_map
+    from jax import shard_map
 
     b, _, h, dh = q.shape
     S, hk = k_cache.shape[1], k_cache.shape[2]
@@ -656,7 +656,7 @@ def _moe_sharded(params, cfg: ModelConfig, x, rules, cf):
     """
     from jax.sharding import PartitionSpec as P
 
-    from repro.sharding.compat import shard_map
+    from jax import shard_map
 
     mesh = rules.mesh
     b, s, d = x.shape

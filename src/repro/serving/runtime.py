@@ -400,7 +400,7 @@ class ServingRuntime:
             self.outcomes[r.rid] = Answer(
                 rid=r.rid, ids=ids[i], dists=dists[i],
                 epoch=epoch.epoch_id, degraded=degraded,
-                latency_ms=latency_ms)
+                latency_ms=latency_ms, engine=res.stats.engine)
         self.stats.batches += 1
         self.stats.queries += len(batch)
         self.stats.pad_queries += pad

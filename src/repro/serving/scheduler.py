@@ -60,6 +60,7 @@ class Answer:
     epoch: int           # epoch id the batch was pinned to
     degraded: bool       # answered at capped max_rounds
     latency_ms: float
+    engine: str = ""     # query engine that served the batch (after retry)
 
 
 REJECT_REASONS = ("deadline", "queue_full", "engine_failure")

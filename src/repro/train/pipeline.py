@@ -18,7 +18,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.sharding.compat import shard_map
+from jax import shard_map
 
 
 def pipeline_apply(stage_params, x_micro, stage_fn, mesh: Mesh,

@@ -145,6 +145,20 @@ def test_engine_failure_retries_on_vmap_with_exact_answers(rng):
 
 
 @pytest.mark.timeout(300)
+def test_answers_name_the_engine_that_served_them(rng):
+    """A retried batch is visible per answer, not only in stats.retries:
+    a fused-engine failure must not pass as a fused answer."""
+    rt, idx, plan = _runtime(rng)
+    data, _ = idx.pin_state().survivors()
+    queries = make_queries_near(data, rng, 8)        # one full batch
+    out = _serve_and_check(rt, idx, queries)
+    assert {o.engine for o in out} == {"fused"} and rt.stats.retries == 0
+    plan.arm(ENGINE_CALL, times=1)
+    out = _serve_and_check(rt, idx, queries)
+    assert {o.engine for o in out} == {"vmap"} and rt.stats.retries == 1
+
+
+@pytest.mark.timeout(300)
 def test_persistent_engine_failure_rejects_only_affected_batch(rng):
     rt, idx, plan = _runtime(rng, max_batch=4)
     data, _ = idx.pin_state().survivors()
