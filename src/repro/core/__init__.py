@@ -22,6 +22,7 @@ import numpy as np
 
 from typing import Optional
 
+from repro import tracing
 from repro.core.theory import LSHParams, derive_params, SUCCESS_PROBABILITY
 from repro.core import hashing, encoding, detree
 from repro.core.detree import DEForest, build_forest
@@ -162,11 +163,17 @@ class DETLSH:
             block_q=spec.block_q if spec is not None else 8,
             block_l=spec.block_l if spec is not None else 8,
             default_probe_depth=spec.probe_depth if spec is not None else 0)
+        batch = queries.shape[0]
         engine = registry.resolve_engine(cfg.engine, mode=cfg.mode,
-                                         batch=queries.shape[0])
+                                         batch=batch)
         plan = self.fused_plan() if engine == "fused" else None
-        res = knn_query_batch(self.data, self.forest, self.A, self.params,
-                              queries, cfg, plan=plan, n_active=req.n_active)
+        with tracing.span("detlsh.search.dispatch", batch=batch,
+                          n_active=(batch if req.n_active is None
+                                    else int(req.n_active)),
+                          engine=engine):
+            res = knn_query_batch(self.data, self.forest, self.A,
+                                  self.params, queries, cfg, plan=plan,
+                                  n_active=req.n_active)
         return SearchResult(
             ids=res.ids, dists=res.dists,
             stats=SearchStats(engine=engine, r_min=float(r_min),

@@ -215,32 +215,33 @@ def assemble_sorted_forest(proj_t: jax.Array, codes_t: jax.Array,
     permutations.  Returns the DEForest arrays (minus breakpoints/statics)
     in their storage dtypes (codes uint8, bounds int16).
     """
-    L, _, K = proj_t.shape
-    n_leaves = -(-n // leaf_size)
-    n_pad = n_leaves * leaf_size
-    pad = n_pad - n
+    with jax.named_scope("assemble"):
+        L, _, K = proj_t.shape
+        n_leaves = -(-n // leaf_size)
+        n_pad = n_leaves * leaf_size
+        pad = n_pad - n
 
-    proj_s = jnp.take_along_axis(proj_t, order[..., None], axis=1)
-    codes_s = jnp.take_along_axis(codes_t.astype(jnp.int32),
-                                  order[..., None], axis=1)
-    proj_s = jnp.pad(proj_s, ((0, 0), (0, pad), (0, 0)))
-    codes_s = jnp.pad(codes_s, ((0, 0), (0, pad), (0, 0)))
-    ids = jnp.pad(order.astype(jnp.int32), ((0, 0), (0, pad)),
-                  constant_values=n)
-    valid = jnp.broadcast_to(jnp.arange(n_pad) < n, (L, n_pad))
+        proj_s = jnp.take_along_axis(proj_t, order[..., None], axis=1)
+        codes_s = jnp.take_along_axis(codes_t.astype(jnp.int32),
+                                      order[..., None], axis=1)
+        proj_s = jnp.pad(proj_s, ((0, 0), (0, pad), (0, 0)))
+        codes_s = jnp.pad(codes_s, ((0, 0), (0, pad), (0, 0)))
+        ids = jnp.pad(order.astype(jnp.int32), ((0, 0), (0, pad)),
+                      constant_values=n)
+        valid = jnp.broadcast_to(jnp.arange(n_pad) < n, (L, n_pad))
 
-    blocks = codes_s.reshape(L, n_leaves, leaf_size, K)
-    bmask = valid.reshape(L, n_leaves, leaf_size)
-    big = jnp.iinfo(jnp.int32).max
-    lo = jnp.where(bmask[..., None], blocks, big).min(axis=2)
-    hi = jnp.where(bmask[..., None], blocks, -1).max(axis=2)
-    leaf_valid = bmask.any(axis=2)
-    lo = jnp.where(leaf_valid[..., None], lo, 0).astype(LEAF_DTYPE)
-    hi = jnp.where(leaf_valid[..., None], hi, 0).astype(LEAF_DTYPE)
+        blocks = codes_s.reshape(L, n_leaves, leaf_size, K)
+        bmask = valid.reshape(L, n_leaves, leaf_size)
+        big = jnp.iinfo(jnp.int32).max
+        lo = jnp.where(bmask[..., None], blocks, big).min(axis=2)
+        hi = jnp.where(bmask[..., None], blocks, -1).max(axis=2)
+        leaf_valid = bmask.any(axis=2)
+        lo = jnp.where(leaf_valid[..., None], lo, 0).astype(LEAF_DTYPE)
+        hi = jnp.where(leaf_valid[..., None], hi, 0).astype(LEAF_DTYPE)
 
-    return dict(point_ids=ids, proj_sorted=proj_s,
-                codes_sorted=codes_s.astype(CODE_DTYPE), valid=valid,
-                leaf_lo=lo, leaf_hi=hi, leaf_valid=leaf_valid)
+        return dict(point_ids=ids, proj_sorted=proj_s,
+                    codes_sorted=codes_s.astype(CODE_DTYPE), valid=valid,
+                    leaf_lo=lo, leaf_hi=hi, leaf_valid=leaf_valid)
 
 
 def check_nr(Nr: int) -> None:

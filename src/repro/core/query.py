@@ -439,9 +439,10 @@ def fused_query_batch(data: jax.Array, forest: DEForest, A: jax.Array,
         # tree's sorted-order row into id order (gather, not scatter).
         # One (B, n) gather per tree: a batched take_along_axis would
         # materialize (L, B, n) index and transpose buffers (GBs at n=1M).
-        by_id = functools.reduce(jnp.minimum, [
-            jnp.take(dmat[l], plan.inv_perm[l], axis=1)
-            for l in range(L)])                                  # (B, n)
+        with jax.named_scope("fold"):
+            by_id = functools.reduce(jnp.minimum, [
+                jnp.take(dmat[l], plan.inv_perm[l], axis=1)
+                for l in range(L)])                              # (B, n)
         best, r, done, rounds = fused_round_update(
             best, by_id, r, done, rounds, rnd, params=params, k=cfg.k,
             thresh=thresh)

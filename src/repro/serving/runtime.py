@@ -27,10 +27,10 @@ surviving set, and pinned epochs keep answering on pre-compaction
 structure, which is exactly what the property test checks
 (tests/test_runtime_properties.py).
 
-Metrics are lock-free on the read path: latencies land in a bounded
-``LatencyRing`` (fixed numpy buffer, monotonic write index) and counters
-are plain ints — single-writer in this in-process model, and safe to read
-at any time without coordination.
+Metrics are lock-free on the read path: latencies and queue waits land
+in bounded ``LatencyRing``s (fixed numpy buffer, monotonic write index)
+and counters are plain ints — single-writer in this in-process model, and
+safe to read at any time without coordination.
 """
 
 from __future__ import annotations
@@ -43,6 +43,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import tracing
 from repro.api.protocol import LegacyIndexAdapter, MutableAnnIndex, \
     as_ann_index
 from repro.api.request import SearchRequest
@@ -104,6 +105,9 @@ class RuntimeStats:
 
     latencies: LatencyRing = dataclasses.field(
         default_factory=lambda: LatencyRing(4096))
+    # per answered query: arrival -> its batch's start (Answer.queue_ms)
+    queue_waits: LatencyRing = dataclasses.field(
+        default_factory=lambda: LatencyRing(4096))
     queries: int = 0            # real served queries — never pad lanes
     batches: int = 0
     pad_queries: int = 0
@@ -161,6 +165,8 @@ class RuntimeStats:
             "p50_ms": self.percentile(50.0),
             "p99_ms": self.percentile(99.0),
             "p999_ms": self.percentile(99.9),
+            "queue_p50_ms": self.queue_waits.percentile(50.0),
+            "queue_p99_ms": self.queue_waits.percentile(99.0),
         }
 
 
@@ -270,7 +276,8 @@ class ServingRuntime:
         self.degraded_max_rounds = degraded_max_rounds
         self.plan = fault_plan or flt.FaultPlan()
         self.stats = RuntimeStats(
-            latencies=LatencyRing(latency_ring_capacity))
+            latencies=LatencyRing(latency_ring_capacity),
+            queue_waits=LatencyRing(latency_ring_capacity))
         self.batcher = MicroBatcher(
             max_batch=max_batch, pad_to=pad_to, max_wait=max_wait_ms / 1e3,
             deadline_headroom=deadline_headroom, queue_cap=queue_cap,
@@ -344,68 +351,77 @@ class ServingRuntime:
         return req
 
     def _run_batch(self) -> None:
-        now = self.clock()
-        batch, degraded, shed = self.batcher.next_batch(now)
-        for rej in shed:
-            self.outcomes[rej.rid] = rej
-            self.stats.record_shed(rej)
-        if not batch:
-            return
+        with tracing.span("detlsh.serve.batch",
+                          batch_id=self.stats.batches) as sp:
+            now = self.clock()
+            batch, degraded, shed = self.batcher.next_batch(now)
+            for rej in shed:
+                self.outcomes[rej.rid] = rej
+                self.stats.record_shed(rej)
+            if not batch:
+                return
 
-        qs = np.stack([r.query for r in batch])
-        pad = self.batcher.bucket(len(qs)) - len(qs)
-        if pad:
-            qs = np.concatenate([qs, np.zeros((pad, qs.shape[1]), qs.dtype)])
-        bucket = qs.shape[0]
-        req = self._make_request(len(batch), degraded)
+            qs = np.stack([r.query for r in batch])
+            pad = self.batcher.bucket(len(qs)) - len(qs)
+            if pad:
+                qs = np.concatenate([qs, np.zeros((pad, qs.shape[1]),
+                                                  qs.dtype)])
+            bucket = qs.shape[0]
+            req = self._make_request(len(batch), degraded)
+            waits_ms = [(now - r.arrival) * 1e3 for r in batch]
+            sp.set(queries=len(batch), pad=pad, bucket=bucket,
+                   degraded=degraded, wait_ms_sum=sum(waits_ms),
+                   wait_ms_max=max(waits_ms))
 
-        epoch = self.epochs.pin()
-        try:
-            t0 = self.clock()
+            epoch = self.epochs.pin()
             try:
-                self.plan.fire(flt.ENGINE_CALL)
-                res = epoch.search(jnp.asarray(qs), req)
-                jax.block_until_ready(res.dists)
-            except Exception as first:
-                # retry once on the vmap semantics-of-record engine; a
-                # second failure rejects only this batch's requests
-                self.stats.retries += 1
-                retry_req = dataclasses.replace(req, engine="vmap")
+                t0 = self.clock()
                 try:
                     self.plan.fire(flt.ENGINE_CALL)
-                    res = epoch.search(jnp.asarray(qs), retry_req)
+                    res = epoch.search(jnp.asarray(qs), req)
                     jax.block_until_ready(res.dists)
-                except Exception as second:
-                    for r in batch:
-                        rej = Rejected(
-                            r.rid, "engine_failure",
-                            f"engine call failed twice: {first!r}; "
-                            f"retry on vmap: {second!r}")
-                        self.outcomes[r.rid] = rej
-                        self.stats.record_shed(rej)
-                    self.stats.batches += 1
-                    return
-            done = self.clock()
-        finally:
-            self.epochs.release(epoch)
+                except Exception as first:
+                    # retry once on the vmap semantics-of-record engine; a
+                    # second failure rejects only this batch's requests
+                    self.stats.retries += 1
+                    retry_req = dataclasses.replace(req, engine="vmap")
+                    try:
+                        self.plan.fire(flt.ENGINE_CALL)
+                        res = epoch.search(jnp.asarray(qs), retry_req)
+                        jax.block_until_ready(res.dists)
+                    except Exception as second:
+                        for r in batch:
+                            rej = Rejected(
+                                r.rid, "engine_failure",
+                                f"engine call failed twice: {first!r}; "
+                                f"retry on vmap: {second!r}")
+                            self.outcomes[r.rid] = rej
+                            self.stats.record_shed(rej)
+                        self.stats.batches += 1
+                        return
+                done = self.clock()
+            finally:
+                self.epochs.release(epoch)
 
-        self.batcher.model.observe(bucket, degraded, max(0.0, done - t0))
-        ids = np.asarray(res.ids)
-        dists = np.asarray(res.dists)
-        for i, r in enumerate(batch):
-            latency_ms = (done - r.arrival) * 1e3
-            self.stats.latencies.append(latency_ms)
-            if r.deadline is not None and done > r.deadline:
-                self.stats.deadline_misses += 1
-            self.outcomes[r.rid] = Answer(
-                rid=r.rid, ids=ids[i], dists=dists[i],
-                epoch=epoch.epoch_id, degraded=degraded,
-                latency_ms=latency_ms, engine=res.stats.engine)
-        self.stats.batches += 1
-        self.stats.queries += len(batch)
-        self.stats.pad_queries += pad
-        if degraded:
-            self.stats.degraded_batches += 1
+            self.batcher.model.observe(bucket, degraded, max(0.0, done - t0))
+            ids = np.asarray(res.ids)
+            dists = np.asarray(res.dists)
+            for i, (r, wait_ms) in enumerate(zip(batch, waits_ms)):
+                latency_ms = (done - r.arrival) * 1e3
+                self.stats.latencies.append(latency_ms)
+                self.stats.queue_waits.append(wait_ms)
+                if r.deadline is not None and done > r.deadline:
+                    self.stats.deadline_misses += 1
+                self.outcomes[r.rid] = Answer(
+                    rid=r.rid, ids=ids[i], dists=dists[i],
+                    epoch=epoch.epoch_id, degraded=degraded,
+                    latency_ms=latency_ms, engine=res.stats.engine,
+                    queue_ms=wait_ms)
+            self.stats.batches += 1
+            self.stats.queries += len(batch)
+            self.stats.pad_queries += pad
+            if degraded:
+                self.stats.degraded_batches += 1
 
     def serve(self, request_stream) -> List[Outcome]:
         """Closed-loop convenience: feed ``(arrival, vec)`` or ``(arrival,

@@ -61,6 +61,7 @@ class Answer:
     degraded: bool       # answered at capped max_rounds
     latency_ms: float
     engine: str = ""     # query engine that served the batch (after retry)
+    queue_ms: float = 0.0  # arrival -> its batch's start, <= latency_ms
 
 
 REJECT_REASONS = ("deadline", "queue_full", "engine_failure")

@@ -275,3 +275,42 @@ def test_runtime_degrades_under_deadline_pressure(rng):
     ans = rt.outcomes[rid]
     assert isinstance(ans, Answer) and ans.degraded
     assert rt.stats.degraded_batches == 1 and rt.stats.shed_total == 0
+
+
+@pytest.mark.timeout(300)
+def test_runtime_splits_latency_into_queue_wait_and_service(rng):
+    """Each answer's queue wait runs from its arrival to its batch's
+    start, the rest of its latency is the batch's service; with a clock
+    that ticks 0.25 s a read, both are exact."""
+    idx = _build_index(rng, n=256)
+    ticks = iter(np.arange(100.0, 200.0, 0.25))
+    rt = ServingRuntime(idx, k=3, max_batch=2, pad_to=2,
+                        clock=lambda: float(next(ticks)),
+                        request=SearchRequest(k=3, **SAT))
+    queries = make_clustered(rng, 3, D)
+    rids = [rt.submit(q, arrival=a)
+            for q, a in zip(queries, (99.0, 99.5, 100.0))]
+    rt.flush()
+    # batch 1 reads 100.0 (start), 100.25, 100.5 (done); batch 2 100.75,
+    # 101.0, 101.25
+    out = [rt.outcomes[r] for r in rids]
+    assert [a.queue_ms for a in out] == [1000.0, 500.0, 750.0]
+    assert [a.latency_ms for a in out] == [1500.0, 1000.0, 1250.0]
+    assert all(a.queue_ms <= a.latency_ms for a in out)
+    assert {a.latency_ms - a.queue_ms for a in out} == {500.0}
+    np.testing.assert_array_equal(rt.stats.queue_waits.values(),
+                                  [1000.0, 500.0, 750.0])
+    s = rt.stats.summary()
+    assert s["queue_p50_ms"] == 750.0
+    assert s["queue_p99_ms"] == float(np.percentile([1000, 500, 750], 99))
+    assert s["queue_p99_ms"] <= s["p99_ms"]
+
+
+def test_queue_wait_ring_shares_the_latency_capacity(rng):
+    idx = _build_index(rng, n=128)
+    rt = ServingRuntime(idx, k=3, latency_ring_capacity=16)
+    assert rt.stats.queue_waits.capacity == 16
+    assert rt.stats.latencies.capacity == 16
+    assert np.isnan(rt.stats.summary()["queue_p50_ms"])
+    assert Answer(rid=0, ids=np.zeros(1), dists=np.zeros(1), epoch=0,
+                  degraded=False, latency_ms=1.0).queue_ms == 0.0
