@@ -152,9 +152,11 @@ class DETLSH:
         r_min, cached = req.r_min, False
         if r_min is None:
             cached = req.k in self._r_min_cache    # hit vs first estimate
-            # Zero-vector pad lanes must not skew the cached estimate
-            # (n_active == 0 keeps the full batch: no real lanes to probe).
-            probes = queries[: req.n_active] if req.n_active else queries
+            probes = None
+            if not cached:
+                # Zero-vector pad lanes must not skew the estimate (n_active
+                # == 0 keeps the full batch: no real lanes to probe).
+                probes = queries[: req.n_active] if req.n_active else queries
             r_min = self.r_min_for(req.k, probes)
         spec = self.spec
         default_engine = spec.engine if spec is not None else "auto"

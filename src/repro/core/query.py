@@ -247,12 +247,14 @@ def _auto_cap(n: int, params: LSHParams, cfg: QueryConfig,
 def knn_query(data: jax.Array, forest: DEForest, A: jax.Array,
               params: LSHParams, q: jax.Array,
               cfg: QueryConfig, *, live: Optional[jax.Array] = None,
-              active: jax.Array | bool = True) -> QueryResult:
+              active: jax.Array | bool = True,
+              r_min: Optional[jax.Array | float] = None) -> QueryResult:
     """Answer one c^2-k-ANN query (Alg. 5).  q: (d,).
 
     ``live`` is an optional (n,) bool tombstone mask (streaming index
     deletes); ``active=False`` marks the lane done from round 0 (used for
     pad lanes in partial batches — the radius loop never runs for them).
+    ``r_min`` (float or traced scalar) overrides ``cfg.r_min``.
     """
     n = data.shape[0]
     K, L = params.K, params.L
@@ -280,7 +282,8 @@ def knn_query(data: jax.Array, forest: DEForest, A: jax.Array,
         r = jnp.where(done, r, r * params.c)                    # line 11
         return rnd + 1, r, cs, done, probed + pl, pcand + pc
 
-    state0 = (jnp.asarray(0, jnp.int32), jnp.asarray(cfg.r_min, jnp.float32),
+    r0 = cfg.r_min if r_min is None else r_min
+    state0 = (jnp.asarray(0, jnp.int32), jnp.asarray(r0, jnp.float32),
               cand.init_state(n, cap), ~jnp.asarray(active),
               jnp.asarray(0, jnp.int32), jnp.asarray(0, jnp.int32))
     rnd, r, cs, done, probed, pcand = jax.lax.while_loop(cond, body, state0)
@@ -363,7 +366,8 @@ def fused_query_batch(data: jax.Array, forest: DEForest, A: jax.Array,
                       cfg: QueryConfig,
                       plan: Optional[FusedPlan] = None, *,
                       live_sorted: Optional[jax.Array] = None,
-                      n_active: Optional[jax.Array | int] = None
+                      n_active: Optional[jax.Array | int] = None,
+                      r_min: Optional[jax.Array | float] = None
                       ) -> QueryResult:
     """Batched c^2-k-ANN: all lanes advance through radius rounds together.
 
@@ -381,7 +385,8 @@ def fused_query_batch(data: jax.Array, forest: DEForest, A: jax.Array,
     points emit +inf inside the kernel and never become candidates.
     ``n_active`` (int or scalar array) marks lanes >= n_active done from
     round 0 with r_eff = -1 — pad lanes of a partial batch admit nothing
-    and skip all MXU work (see serving/lsh_service.py).
+    and skip all MXU work (see serving/lsh_service.py).  ``r_min`` (float
+    or traced scalar) overrides ``cfg.r_min``.
 
     With ``cfg.probe_depth > 0`` the leaf-LB table (radius-independent) is
     computed once up front and every round widens each lane's radius
@@ -452,7 +457,8 @@ def fused_query_batch(data: jax.Array, forest: DEForest, A: jax.Array,
              else jnp.arange(B) >= jnp.asarray(n_active))
     state0 = (jnp.asarray(0, jnp.int32),
               jnp.zeros((B,), jnp.int32),
-              jnp.full((B,), cfg.r_min, jnp.float32),
+              jnp.full((B,), cfg.r_min if r_min is None else r_min,
+                       jnp.float32),
               done0,
               jnp.full((B, n), jnp.inf, jnp.float32),
               jnp.zeros((B,), jnp.int32),
@@ -480,28 +486,60 @@ def live_in_sorted_order(forest: DEForest,
     return live[safe] & forest.valid
 
 
+def _program_operands(queries, cfg: QueryConfig, engine: str, n_active):
+    """Split a search into its static key and traced scalars.
+
+    The starting radius and the active-lane count change from call to call
+    (a per-request ``r_min``, a serve bucket's fill level), so they travel
+    as traced f32 / int32 scalars and ``cfg`` keeps one fixed ``r_min``
+    (and the resolved engine name): every such call reuses the program
+    compiled for its shapes.  ``n_active=None`` means every lane is live.
+    """
+    r_min = jnp.asarray(cfg.r_min, jnp.float32)
+    n_act = jnp.asarray(queries.shape[0] if n_active is None else n_active,
+                        jnp.int32)
+    return dataclasses.replace(cfg, r_min=1.0, engine=engine), r_min, n_act
+
+
+@functools.partial(jax.jit, static_argnames=("params", "cfg"))
+def _vmap_program(data, forest, A, queries, live, r_min, n_active, *,
+                  params: LSHParams, cfg: QueryConfig) -> QueryResult:
+    active = jnp.arange(queries.shape[0]) < n_active
+    fn = functools.partial(knn_query, data, forest, A, params, cfg=cfg,
+                           live=live, r_min=r_min)
+    return jax.vmap(lambda q, a: fn(q, active=a))(queries, active)
+
+
+@functools.partial(jax.jit, static_argnames=("params", "cfg"))
+def _fused_program(data, forest, A, queries, plan, live, live_sorted, r_min,
+                   n_active, *, params: LSHParams,
+                   cfg: QueryConfig) -> QueryResult:
+    if live_sorted is None and live is not None:
+        live_sorted = live_in_sorted_order(forest, live)
+    return fused_query_batch(data, forest, A, params, queries, cfg,
+                             plan=plan, live_sorted=live_sorted,
+                             n_active=n_active, r_min=r_min)
+
+
 def _run_vmap_engine(data, forest, A, params, queries, cfg, *,
                      plan=None, live=None, live_sorted=None,
                      n_active=None) -> QueryResult:
-    """Registry entry point for engine='vmap' (ignores plan/live_sorted)."""
+    """Registry entry point for engine='vmap' (ignores plan/live_sorted):
+    one compiled program per (shapes, static config)."""
     del plan, live_sorted
-    B = queries.shape[0]
-    active = (jnp.ones((B,), jnp.bool_) if n_active is None
-              else jnp.arange(B) < jnp.asarray(n_active))
-    fn = functools.partial(knn_query, data, forest, A, params, cfg=cfg,
-                           live=live)
-    return jax.vmap(lambda q, a: fn(q, active=a))(queries, active)
+    cfg, r_min, n_act = _program_operands(queries, cfg, "vmap", n_active)
+    return _vmap_program(data, forest, A, queries, live, r_min, n_act,
+                         params=params, cfg=cfg)
 
 
 def _run_fused_engine(data, forest, A, params, queries, cfg, *,
                       plan=None, live=None, live_sorted=None,
                       n_active=None) -> QueryResult:
-    """Registry entry point for engine='fused' (derives live_sorted)."""
-    if live_sorted is None and live is not None:
-        live_sorted = live_in_sorted_order(forest, live)
-    return fused_query_batch(data, forest, A, params, queries, cfg,
-                             plan=plan, live_sorted=live_sorted,
-                             n_active=n_active)
+    """Registry entry point for engine='fused' (derives live_sorted): one
+    compiled program per (shapes, static config)."""
+    cfg, r_min, n_act = _program_operands(queries, cfg, "fused", n_active)
+    return _fused_program(data, forest, A, queries, plan, live, live_sorted,
+                          r_min, n_act, params=params, cfg=cfg)
 
 
 engine_registry.register_engine(
@@ -528,7 +566,10 @@ def knn_query_batch(data: jax.Array, forest: DEForest, A: jax.Array,
 
     Dispatches through the ``repro.api.registry`` engine registry (fused
     by default at batch >= 8, vmap otherwise / for 'strict') according to
-    ``cfg.engine`` / ``cfg.mode`` and the (static) batch size.
+    ``cfg.engine`` / ``cfg.mode`` and the (static) batch size.  The
+    engine is resolved here, on the host; each built-in engine then runs
+    one jitted program per (shapes, static config), reused by every later
+    call whatever its ``r_min`` and ``n_active`` (docs/DESIGN.md §3).
 
     ``live`` ((n,) bool, id order) / ``live_sorted`` ((L, n_pad) bool,
     code-sorted order) carry the streaming index's tombstones — pass either

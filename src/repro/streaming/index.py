@@ -638,16 +638,18 @@ class StreamingDETLSH:
                    or view.fingerprint == (self.manifest.version,
                                            self.memtable.version))
         if r_min is None:
-            # Zero-vector pad lanes must not skew the cached estimate
-            # (n_active == 0 keeps the full batch: no real lanes to probe).
-            probes = queries[: req.n_active] if req.n_active else queries
+            cached = (self._rmin_hit(req.k) if current    # hit vs first
+                      else req.k in view._rmin)           # estimate
+            probes = None
+            if not cached:
+                # Zero-vector pad lanes must not skew the estimate (n_active
+                # == 0 keeps the full batch: no real lanes to probe).
+                probes = queries[: req.n_active] if req.n_active else queries
             if current:
-                cached = self._rmin_hit(req.k)        # hit vs first estimate
                 r_min = self.r_min_for(req.k, probes)
                 if view is not None:
                     view._rmin.setdefault(req.k, r_min)
             else:
-                cached = req.k in view._rmin
                 r_min = self._view_rmin(view, req.k, probes)
         res = self._fanout_query(queries, req, float(r_min),
                                  view if view is not None

@@ -111,6 +111,38 @@ def test_registry_round_trip_custom_engine():
     assert resolve_engine("auto", mode="leaf", batch=64) == "fused"
 
 
+def test_custom_engine_registered_after_a_compiled_search_is_called(
+        static_idx):
+    """Engine resolution stays on the host: an engine registered after the
+    fused engine compiled its program still takes over ``'auto'``, and need
+    not be traceable."""
+    from repro.api import get_engine, register_engine
+    from repro.api import registry as reg
+    idx, data, rng = static_idx
+    q = jnp.asarray(make_queries_near(data, rng, 16))
+    req = SearchRequest(k=5, r_min=0.5)
+    fused = idx.search(q, req)
+    assert fused.stats.engine == "fused"
+    calls = []
+
+    def run(*a, **kw):
+        calls.append(kw["n_active"])
+        res = get_engine("vmap").run(*a, **kw)
+        np.asarray(res.ids)                  # host work: not traceable
+        return res
+
+    register_engine("test-late", run, modes=("leaf",), min_batch=1,
+                    priority=99)
+    try:
+        res = idx.search(q, req)
+    finally:
+        del reg._ENGINES["test-late"]
+    assert calls == [None] and res.stats.engine == "test-late"
+    vmap = idx.search(q, SearchRequest(k=5, r_min=0.5, engine="vmap"))
+    np.testing.assert_array_equal(np.asarray(res.ids), np.asarray(vmap.ids))
+    assert idx.search(q, req).stats.engine == "fused"
+
+
 # ---------------------------------------------------------------------------
 # Protocol conformance (acceptance criterion)
 # ---------------------------------------------------------------------------

@@ -174,6 +174,40 @@ def test_runtime_serves_exact_answers_and_counts(rng):
 
 
 @pytest.mark.timeout(300)
+def test_warm_runtime_compiles_nothing_at_any_fill_level(rng):
+    """After ``warmup`` every bucket's program is compiled: a batch of any
+    fill level reuses its bucket's program (``n_active`` is a traced
+    operand), so serving traces, lowers and compiles nothing."""
+    from repro.core import DETLSH
+    events = []
+
+    def on_duration(event, duration, **_):
+        if event.startswith("/jax/core/compile/"):
+            events.append(event)
+
+    data = make_clustered(rng, 1024, D)
+    idx = DETLSH.build(jnp.asarray(data), jax.random.key(0),
+                       derive_params(K=4, c=1.5, L=4, beta_override=0.1),
+                       leaf_size=16)
+    rt = ServingRuntime(idx, k=5, max_batch=16, pad_to=8, max_wait_ms=1e6)
+    rt.warmup(D)
+    queries = make_queries_near(data, rng, 16)
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    try:
+        for fill in range(1, 17):
+            rids = [rt.submit(q) for q in queries[:fill]]
+            assert rt.flush() == 1
+            assert all(isinstance(rt.outcomes[r], Answer) for r in rids)
+    finally:
+        jax.monitoring.unregister_event_duration_listener(on_duration)
+    assert events == []
+    s = rt.stats.summary()
+    assert s["batches"] == 16 and s["queries"] == 136
+    assert s["pad_queries"] == sum((8 if f <= 8 else 16) - f
+                                   for f in range(1, 17))
+
+
+@pytest.mark.timeout(300)
 def test_pinned_epoch_survives_concurrent_compaction(rng):
     """Satellite: compaction triggered concurrently with an in-flight
     pinned epoch does not invalidate that epoch's answers."""

@@ -19,7 +19,7 @@ from jax.profiler import ProfileData, TraceAnnotation
 
 from repro import tracing
 from repro.api import SearchRequest
-from repro.core import DETLSH, derive_params, detree, estimate_r_min
+from repro.core import DETLSH, derive_params, detree, estimate_r_min, query
 from repro.core.query import QueryConfig, fused_query_batch, make_fused_plan
 from repro.serving import Answer, ServingRuntime
 from tests.conftest import make_clustered
@@ -146,6 +146,24 @@ def test_search_dispatch_span_carries_batch_and_engine(built, tmp_path):
     assert args["engine"] == "fused"
 
 
+def test_warm_dispatch_span_reads_no_compile_work(built, tmp_path):
+    """A warm search runs its compiled program: the dispatch span, which
+    ``search.lower_ms_per_batch`` reads, charges it no trace, lowering or
+    compile, whatever the request's radius or active lanes."""
+    idx, queries, r0 = built
+    q = jnp.asarray(queries)
+    idx.search(q, SearchRequest(k=10, r_min=r0, engine="fused"))
+    with _profiled(tmp_path) as d:
+        for scale, n_active in ((1.0, 16), (2.0, 5), (0.5, 9)):
+            idx.search(q, SearchRequest(k=10, r_min=r0 * scale,
+                                        n_active=n_active, engine="fused"))
+    spans = _spans(d)
+    assert [s[0] for s in spans] == ["detlsh.search.dispatch"] * 3
+    for _, _, _, args in spans:
+        assert args["traces"] == args["compiles"] == 0, args
+        assert args["trace_ms"] == args["lower_ms"] == 0, args
+
+
 def test_serve_batch_span_sums_each_requests_queue_wait(built, tmp_path):
     idx, queries, r0 = built
     ticks = iter(np.arange(100.0, 200.0, 0.25))
@@ -197,9 +215,17 @@ def _assemble_lowered(L=2, n=100, K=3):
 
 @contextlib.contextmanager
 def _no_scopes(monkeypatch):
+    # the search's compiled program is traced anew inside and after
+    programs = (query._fused_program, query._vmap_program)
     with monkeypatch.context() as m:
         m.setattr(jax, "named_scope", lambda name: contextlib.nullcontext())
-        yield
+        for program in programs:
+            program.clear_cache()
+        try:
+            yield
+        finally:
+            for program in programs:
+                program.clear_cache()
 
 
 def test_fold_scope_is_in_the_fused_search(built):
